@@ -1306,6 +1306,8 @@ def metrics_from_rows(rows) -> dict:
 
 
 def main(argv: list[str] | None = None) -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args = list(argv if argv is not None else sys.argv[1:])
     json_path = None
     if "--json" in args:

@@ -328,6 +328,14 @@ class CompiledNetwork:
                 self(prepared, jnp.zeros(s, jnp.float32), donate=donate))
         return self.exec_stats()
 
+    def compiled_text(self, prepared, shape) -> str:
+        """Text of the program the serving path (the donating variant)
+        compiles for one input shape; on a TPU each Pallas kernel shows
+        as a ``tpu_custom_call`` named after its ``pallas_call``."""
+        x = jax.ShapeDtypeStruct(tuple(shape), jnp.float32)
+        return (self._jitted_donate.lower(_unwrap(prepared), x)
+                .compile().as_text())
+
     def exec_stats(self) -> dict:
         with self._stats_lock:
             return dict(self._exec)
@@ -406,21 +414,11 @@ class PipelinedEngine:
     def _dispatch(self, slices, x, env, s: int):
         stage = self.stages[s]
         # per-stage fault site: "fail stage k of batch n" is expressible,
-        # and the raised fault carries the stage's device tag so failures
-        # are attributable to the FPGA or GPU path
+        # and the injected fault carries the stage's device tag.  A real
+        # failure stays untagged: it is an error, never a failover
         faults.trip("stage", device=stage.device, stage=s)
         xin = x if stage.needs_input else ()
-        try:
-            return self._jitted[s](slices[s], xin, env)
-        except Exception as e:
-            # attribute real stage failures too (best effort: some
-            # exception types reject new attributes)
-            try:
-                e.device = getattr(e, "device", None) or stage.device
-                e.stage = s
-            except AttributeError:
-                pass
-            raise
+        return self._jitted[s](slices[s], xin, env)
 
     def _count_call(self, x, donated_env_bytes: int) -> None:
         key = (tuple(x.shape), str(getattr(x, "dtype", "f32")))

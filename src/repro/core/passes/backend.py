@@ -15,9 +15,11 @@ Consumes the ``ModuleIR`` produced by annotate/fuse/calibrate and returns a
 Batch invariance (the serving contract): every run-time step is
 row-independent in the batch dimension.  Activation quantization is either
 per-sample (``axis=0``) or a frozen per-tensor constant; the int8 GEMM
-accumulates order-exactly; and the remaining fp32 GEMMs run in fixed row
+accumulates order-exactly; the remaining fp32 GEMMs run in fixed row
 tiles (``rowsafe_matmul``) because XLA:CPU picks gemm blocking from the
-full operand shapes and different blockings round differently.
+full operand shapes and different blockings round differently; and the
+global average pool sums in a fixed order (``gap_rowsafe``) because
+XLA:TPU picks its reduction order by batch size.
 """
 from __future__ import annotations
 
@@ -323,6 +325,21 @@ def _pool_shift(x, k: int, s: int, fill, combine):
     return acc
 
 
+def gap_rowsafe(x):
+    """Global average pool as a fixed chain of elementwise adds, down H
+    and then across W: each output sums its own H*W terms in one order at
+    every batch size.  A ``mean`` leaves that order to the backend, and
+    XLA:TPU picks a different one for a batch of 1 than for a batch of 8
+    (a 14x14 pool then differs in the last bit)."""
+    s = x[:, 0]
+    for i in range(1, x.shape[1]):
+        s = s + x[:, i]
+    t = s[:, 0]
+    for j in range(1, x.shape[2]):
+        t = t + s[:, j]
+    return (t / (x.shape[1] * x.shape[2]))[:, None, None, :]
+
+
 def _lower_pointfree(n: Node):
     """Parameter-free ops (pool/gap/concat/add/split/shuffle)."""
     spec = n.spec
@@ -336,7 +353,7 @@ def _lower_pointfree(n: Node):
             return s / (spec.k * spec.k)
         return run
     if kind == "gap":
-        return lambda xs: xs[0].mean(axis=(1, 2), keepdims=True)
+        return lambda xs: gap_rowsafe(xs[0])
     if kind == "concat":
         return lambda xs: jnp.concatenate(xs, axis=-1)
     if kind == "add":

@@ -186,7 +186,6 @@ class ProcWorker:
         src = os.path.join(os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__)))))
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
         self.proc = subprocess.Popen(
             [sys.executable, "-m", "repro.frontend.worker",
              "--spec", json.dumps(self.spec)],

@@ -16,7 +16,7 @@ Spec schema (everything optional but ``networks``):
 
     {"networks": [{"kind": "zoo",  "name": "mobilenetv2", "res": [32, 32],
                    "seed": 0, "buckets": [1, 4, 8], "pipelined": false,
-                   "paper_faithful": true},
+                   "paper_faithful": true, "replicas": 1, "as": "mbv2"},
                   {"kind": "fire", "name": "tiny", "hw": [8, 8],
                    "c_in": 16, "squeeze": 4, "expand": 8, "seed": 0}],
      "server":  {"max_wait_ms": 2.0, "max_queue": 64, "in_flight": 1},
@@ -41,6 +41,7 @@ import signal
 import sys
 
 from repro.frontend.app import DRAIN_BUDGET_S, FrontDoor, LocalBackend
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def _build_mods(net: dict):
@@ -90,6 +91,7 @@ def build_server(spec: dict):
         server.register(_register_name(net), mods, plans, params,
                         input_hw=tuple(int(v) for v in hw),
                         pipelined=bool(net.get("pipelined", False)),
+                        replicas=int(net.get("replicas", 1)),
                         **kwargs)
     return server.start()
 
@@ -150,6 +152,7 @@ def main(argv=None) -> int:
         ap.error("--spec or --spec-file is required")
     spec = (json.loads(args.spec) if args.spec
             else json.load(open(args.spec_file)))
+    enable_compile_cache()
     return asyncio.run(_serve(spec))
 
 

@@ -16,8 +16,8 @@ Chain shapes (all static, burned into the kernel at trace time):
     activation is applied by the caller.
 
 The kernel takes the UNPADDED input block and SAME-pads the depthwise
-input in VMEM (padding must happen after the leading pointwise stage:
-``act(0 @ w + b)`` is not zero at pad positions).
+input in a VMEM scratch ref (padding must happen after the leading
+pointwise stage: ``act(0 @ w + b)`` is not zero at pad positions).
 """
 from __future__ import annotations
 
@@ -26,6 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _act(x, kind: str):
@@ -36,13 +37,13 @@ def _act(x, kind: str):
     return x
 
 
-def _chain_kernel(refs, *, has_lead: bool, stride: int, act_lead: str,
+def _chain_kernel(*refs, has_lead: bool, stride: int, act_lead: str,
                   act_dw: str):
     if has_lead:
-        x_ref, lw_ref, lb_ref, dww_ref, dwb_ref, pww_ref, pwb_ref, out_ref \
-            = refs
+        (x_ref, lw_ref, lb_ref, dww_ref, dwb_ref, pww_ref, pwb_ref, out_ref,
+         hp_ref) = refs
     else:
-        x_ref, dww_ref, dwb_ref, pww_ref, pwb_ref, out_ref = refs
+        x_ref, dww_ref, dwb_ref, pww_ref, pwb_ref, out_ref, hp_ref = refs
     x = x_ref[0]                            # (H, W, C) unpadded, in VMEM
     H, W = x.shape[0], x.shape[1]
     Ho, Wo = out_ref.shape[1], out_ref.shape[2]
@@ -53,17 +54,27 @@ def _chain_kernel(refs, *, has_lead: bool, stride: int, act_lead: str,
         h = h.reshape(H, W, -1)
     else:
         h = x.astype(jnp.float32)
-    # SAME pad for the 3x3/stride window (XLA's lo=total//2 split)
-    ph = max((Ho - 1) * stride + 3 - H, 0)
-    pw = max((Wo - 1) * stride + 3 - W, 0)
-    hp = jnp.pad(h, ((ph // 2, ph - ph // 2), (pw // 2, pw - pw // 2),
-                     (0, 0)))
+    # SAME pad for the 3x3/stride window (XLA's lo=total//2 split): the
+    # padded map lives in a VMEM scratch ref, and each tap is a strided
+    # load from it (Mosaic lowers a strided slice of a VALUE as a gather,
+    # which it refuses)
+    Hp, Wp, Cm = hp_ref.shape
+    top = (Hp - H) // 2
+    left = (Wp - W) // 2
+    # zero only the pad border; the interior is overwritten below
+    for r0, r1 in ((0, top), (top + H, Hp)):
+        if r1 > r0:
+            hp_ref[r0:r1, :, :] = jnp.zeros((r1 - r0, Wp, Cm), jnp.float32)
+    for c0, c1 in ((0, left), (left + W, Wp)):
+        if c1 > c0:
+            hp_ref[:, c0:c1, :] = jnp.zeros((Hp, c1 - c0, Cm), jnp.float32)
+    hp_ref[top:top + H, left:left + W, :] = h
     dww = dww_ref[...]
-    acc = jnp.zeros((Ho, Wo, hp.shape[-1]), jnp.float32)
+    acc = jnp.zeros((Ho, Wo, Cm), jnp.float32)
     for dy in range(3):
         for dx in range(3):
-            sl = hp[dy:dy + (Ho - 1) * stride + 1:stride,
-                    dx:dx + (Wo - 1) * stride + 1:stride, :]
+            sl = hp_ref[pl.ds(dy, Ho, stride=stride),
+                        pl.ds(dx, Wo, stride=stride), :]
             acc += sl * dww[dy, dx][None, None, :]
     h2 = _act(acc + dwb_ref[...][None, None, :], act_dw)
     # pointwise: (Ho*Wo, C) @ (C, Co) on the MXU, fp32 accumulation
@@ -84,9 +95,9 @@ def fused_chain_pallas(x, lead_w, lead_b, dw_w, dw_b, pw_w, pw_b, *,
     Cm = dw_w.shape[-1]
     Co = pw_w.shape[-1]
     has_lead = lead_w is not None
-    kernel = functools.partial(
-        lambda *refs, **kw: _chain_kernel(refs, **kw),
-        has_lead=has_lead, stride=stride, act_lead=act_lead, act_dw=act_dw)
+    kernel = functools.partial(_chain_kernel, has_lead=has_lead,
+                               stride=stride, act_lead=act_lead,
+                               act_dw=act_dw)
     in_specs = [pl.BlockSpec((1, H, W, C), lambda b: (b, 0, 0, 0))]
     args = [x]
     if has_lead:
@@ -100,13 +111,18 @@ def fused_chain_pallas(x, lead_w, lead_b, dw_w, dw_b, pw_w, pw_b, *,
         pl.BlockSpec((Co,), lambda b: (0,)),
     ]
     args += [dw_w, dw_b, pw_w, pw_b]
+    # SAME-padded depthwise input: Hp = (Ho-1)*stride + 3 when that exceeds H
+    hp = max((Ho - 1) * stride + 3, H)
+    wp = max((Wo - 1) * stride + 3, W)
     return pl.pallas_call(
         kernel,
         grid=(B,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, Ho, Wo, Co), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Ho, Wo, Co), x.dtype),
+        scratch_shapes=[pltpu.VMEM((hp, wp, Cm), jnp.float32)],
         interpret=interpret,
+        name="fused_chain",
     )(*args)
 
 

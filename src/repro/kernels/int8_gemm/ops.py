@@ -40,8 +40,8 @@ def int8_gemm(x_q, w_q, x_scale, w_scale, use_pallas: bool = True,
     mp, np_ = _ceil_to(M, tm), _ceil_to(N, tn)
     xp = jnp.pad(x_q, ((0, mp - M), (0, 0)))
     wp = jnp.pad(w_q, ((0, 0), (0, np_ - N)))
-    ws = jnp.pad(jnp.asarray(w_scale, jnp.float32).reshape(-1),
-                 (0, np_ - N))
+    ws = jnp.pad(jnp.asarray(w_scale, jnp.float32).reshape(1, -1),
+                 ((0, 0), (0, np_ - N)))
     xs_rows = jnp.pad(jnp.broadcast_to(xs.reshape(-1, 1), (M, 1)),
                       ((0, mp - M), (0, 0)))
     out = int8_gemm_pallas(xp, wp, xs_rows, ws, tm=tm, tn=tn,

@@ -25,17 +25,6 @@ from repro.models.lm.common import Schema, ffn_apply, ffn_schema, prefix_schema
 from repro.models.lm.sharding import current_rules
 
 
-def _shard_map(body, mesh, in_specs, out_specs):
-    """jax.shard_map (jax >= 0.5, check_vma) vs experimental shard_map
-    (jax 0.4.x, check_rep) — same semantics, replication check off."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 def _pad_experts(m: MoEConfig, n_ep: int) -> int:
     """Experts padded up to a multiple of the EP group count."""
     e = m.n_routed
@@ -311,10 +300,10 @@ def moe_ep(p, x, m: MoEConfig, local_compute: str = "scan"):
             return _ep_local(x_, wr_, wg_, wu_, wd_, m, ep_axes, n_ep,
                              local_compute, tok_axes)
 
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), e_spec, e_spec, e_spec),
-        out_specs=out_specs,
+        out_specs=out_specs, check_vma=False,
     )(xp, p["router"]["w"], p["experts"]["w_gate"], p["experts"]["w_up"],
       p["experts"]["w_down"])
     y = lc(y, "tokens", None)

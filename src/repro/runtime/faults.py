@@ -88,8 +88,8 @@ class FaultEvent(NamedTuple):
 
 def fault_device(exc: BaseException) -> str | None:
     """The device a failure is attributed to, if any.  ``InjectedFault``
-    carries it directly; real exceptions raised inside a pipelined stage
-    are tagged by the engine's dispatch wrapper."""
+    carries it directly; a real exception carries none, so a kernel that
+    fails on the device counts as an error and never as a failover."""
     dev = getattr(exc, "device", None)
     return dev if isinstance(dev, str) else None
 

@@ -285,6 +285,7 @@ class _Entry:
         self.breaker = breaker or _Breaker()
         self.monitor = StragglerMonitor(threshold=straggler_factor)
         self._seq = 0
+        self.register_s = 0.0       # compile + prepare + bucket warm-up
 
     def _wrap(self, eng):
         """Stripe an engine across this entry's mesh when replicated;
@@ -527,6 +528,7 @@ class HeteroServer:
         requires at least R devices (``make_production_mesh(shape=(R,))``).
         Returns the engine's exec stats after warm-up (one trace per
         bucket x resolution, per replica)."""
+        t0 = time.perf_counter()
         if params is None:
             params = init_network(mods, jax.random.PRNGKey(0))
         if use_pallas is None:
@@ -546,11 +548,23 @@ class HeteroServer:
             self._caps[name] = entry.buckets
             self._max_replicas = max(self._max_replicas, entry.replicas)
         self.metrics.set_breaker(name, entry.breaker.label)
-        return entry.warmup() if warm else entry.engine.exec_stats()
+        out = entry.warmup() if warm else entry.engine.exec_stats()
+        entry.register_s = time.perf_counter() - t0
+        return out
 
     def networks(self) -> list[str]:
         with self._lock:
             return list(self._entries)
+
+    def active(self, name: str):
+        """(engine, prepared) serving ``name`` right now: a batch-1
+        ``engine(prepared, x)`` call reproduces any row served under it."""
+        with self._lock:
+            entry = self._entries.get(name)
+        if entry is None:
+            raise KeyError(f"unregistered network {name!r}; "
+                           f"registered: {self.networks()}")
+        return entry.active()
 
     def swap_params(self, name: str, params, *, calib_x=None) -> dict:
         """Hot-swap a registered network's weights without draining.
@@ -899,7 +913,8 @@ class HeteroServer:
                 try:
                     entry.failover()
                 except Exception:
-                    pass     # fallback build failed: fall to the retry path
+                    # fallback build failed: counted, then the retry path
+                    self.metrics.count("errors")
                 else:
                     self.metrics.count("failovers")
                     self._flush(lane, reqs, by_deadline)  # budget-free retry
@@ -1112,6 +1127,7 @@ class HeteroServer:
                               "replica_count": e.replicas,
                               "ema_left": e.ema_left,
                               "devices": e.engine.devices,
+                              "register_s": e.register_s,
                               "mode": e.mode,
                               "breaker": e.breaker.label,
                               "fallback_ready": e.fb_engine is not None}

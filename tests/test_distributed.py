@@ -13,12 +13,18 @@ from pathlib import Path
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
+# every mesh axis Auto: jax.make_mesh defaults to Explicit axes, which
+# with_sharding_constraint under the LM sharding rules refuses
+_PRELUDE = "import jax\nAUTO = (jax.sharding.AxisType.Auto,)\n"
+
+
 def run_py(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env.pop("JAX_PLATFORMS", None)
-    out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run([sys.executable, "-c",
+                          _PRELUDE + textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=600)
     assert out.returncode == 0, out.stderr[-3000:]
@@ -35,7 +41,7 @@ def test_moe_ep_matches_dense_oracle():
         from repro.models.lm.sharding import AxisRules, use_rules
 
         mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             devices=jax.devices()[:8])
+                             devices=jax.devices()[:8], axis_types=AUTO * 2)
         m = MoEConfig(n_routed=8, top_k=2, d_ff_expert=32, n_shared=1,
                       d_ff_shared=32, capacity_factor=4.0,
                       ep_axes=("model",), dispatch="ep")
@@ -80,7 +86,7 @@ def test_sharded_train_step_matches_single_device():
         s1, m1 = jax.jit(step)(state, batch)
         # sharded over a (2, 4) mesh
         mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             devices=jax.devices()[:8])
+                             devices=jax.devices()[:8], axis_types=AUTO * 2)
         rules = AxisRules(mesh, cfg.policy, cfg.moe)
         with mesh, use_rules(rules):
             s2, m2 = jax.jit(step)(state, batch)
@@ -100,14 +106,16 @@ def test_elastic_restore_across_topologies(tmp_path):
 
         state = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
                   "step": jnp.asarray(3)}}
-        mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4],
+                              axis_types=AUTO)
         sh4 = {{"w": NamedSharding(mesh4, P("data", None)),
                 "step": NamedSharding(mesh4, P())}}
         state4 = jax.tree.map(jax.device_put, state, sh4)
         ck = CheckpointManager(r"{tmp_path}")
         ck.save(3, state4, blocking=True)
 
-        mesh8 = jax.make_mesh((8,), ("data",), devices=jax.devices()[:8])
+        mesh8 = jax.make_mesh((8,), ("data",), devices=jax.devices()[:8],
+                              axis_types=AUTO)
         sh8 = {{"w": NamedSharding(mesh8, P(None, "data")),
                 "step": NamedSharding(mesh8, P())}}
         restored, step = ck.restore(None, state, sh8)
@@ -140,7 +148,7 @@ def test_moe_ep2_hierarchical_matches_dense_oracle():
         from repro.models.lm.sharding import AxisRules, use_rules
 
         mesh = jax.make_mesh((2, 4), ("data", "model"),
-                             devices=jax.devices()[:8])
+                             devices=jax.devices()[:8], axis_types=AUTO * 2)
         m = MoEConfig(n_routed=8, top_k=2, d_ff_expert=32, n_shared=0,
                       capacity_factor=4.0, ep_axes=("data", "model"),
                       dispatch="ep2")

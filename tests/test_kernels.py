@@ -35,11 +35,15 @@ def test_fused_block_matches_ref(shape, dtype):
 
 @pytest.mark.parametrize("mkn", [(128, 64, 128), (256, 128, 256),
                                  (512, 256, 128), (128, 257, 384)])
-def test_int8_gemm_matches_ref(mkn):
+@pytest.mark.parametrize("per_row", [False, True])
+def test_int8_gemm_matches_ref(mkn, per_row):
+    """Per-tensor and per-row activation scales: the (tm, 1) row-scale and
+    (1, tn) channel-scale blocks must line up with their output tiles."""
     M, K, N = mkn
     a = jax.random.normal(jax.random.PRNGKey(1), (M, K))
+    a = a * jnp.linspace(0.1, 4.0, M)[:, None]      # rows of distinct scale
     w = jax.random.normal(jax.random.PRNGKey(2), (K, N))
-    aq, asc = quantize(a)
+    aq, asc = quantize(a, axis=0) if per_row else quantize(a)
     wq, wsc = quantize(w, axis=-1)
     out = int8_gemm_pallas(aq, wq, asc, wsc.reshape(-1), tm=128, tn=128,
                            interpret=True)

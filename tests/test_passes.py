@@ -1,8 +1,9 @@
 """Pass-pipeline tests: chain-fusion grouping, generalized fused-chain
-kernel parity (stride-2 depthwise, pw-dw-pw branches), and fused-chain
-coverage of the three paper networks."""
+kernel parity (stride-2 depthwise, pw-dw-pw branches), fused-chain
+coverage of the three paper networks, and the batch-invariant pool."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core.executor import compile_network
@@ -11,6 +12,7 @@ from repro.core.hetero import init_network, run_network
 from repro.core.partitioner import (candidates, fused_chain_coverage,
                                     partition_network)
 from repro.core.passes import build_ir, chain_groups
+from repro.core.passes.backend import gap_rowsafe
 from repro.kernels.fused_block.ops import fused_chain
 
 
@@ -138,7 +140,10 @@ def test_stride2_chain_pallas_matches_xla_lowering():
 # --- fused_chain kernel odd shapes -----------------------------------------
 
 @pytest.mark.parametrize("hw,stride,lead", [
-    ((9, 7), 2, False), ((8, 8), 1, True), ((11, 9), 2, True)])
+    ((9, 7), 2, False), ((8, 8), 1, True), ((11, 9), 2, True),
+    # even maps at stride 2 pad only below/right, as ShuffleNetV2's
+    # down-sampling chains (56/28/14) do
+    ((14, 14), 2, False), ((16, 12), 2, True), ((7, 7), 1, False)])
 def test_fused_chain_kernel_odd_shapes(hw, stride, lead):
     H, W = hw
     C, Cm, Co = 8, 12, 16
@@ -158,6 +163,20 @@ def test_fused_chain_kernel_odd_shapes(hw, stride, lead):
     Ho, Wo = -(-H // stride), -(-W // stride)
     assert out.shape == (2, Ho, Wo, Co)
     assert float(jnp.abs(out - ref).max()) < 1e-4
+
+
+# --- global average pool ---------------------------------------------------
+
+@pytest.mark.parametrize("hw", [(7, 7), (13, 13), (14, 14)])
+def test_gap_rowsafe_matches_mean_at_every_batch_size(hw):
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, *hw, 40))
+    gap = jax.jit(gap_rowsafe)
+    out = np.asarray(gap(x))
+    ref = np.asarray(x.mean(axis=(1, 2), keepdims=True))
+    assert out.shape == (8, 1, 1, 40)
+    assert np.abs(out - ref).max() < 1e-6
+    rows = np.concatenate([np.asarray(gap(x[i:i + 1])) for i in range(8)])
+    assert rows.tobytes() == out.tobytes()
 
 
 # --- coverage accounting ---------------------------------------------------
