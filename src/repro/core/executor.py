@@ -100,6 +100,12 @@ def _quiet_donation():
         yield
 
 
+def _variants(fn) -> int:
+    """How many compiled variants a ``jax.jit`` function holds: one per
+    argument signature it has dispatched, placements included."""
+    return fn._cache_size()
+
+
 def plan_signature(mods: list[ModuleGraph], plans: list[Plan] | None,
                    use_pallas: bool) -> tuple:
     """Hashable signature of everything lowering depends on: the graph
@@ -200,8 +206,11 @@ class CompiledNetwork:
     ``jax.jit`` still traces once per distinct input SHAPE — a serving
     layer that pads requests into bucket-sized batches should ``warmup``
     each bucket shape ahead of traffic so no live request ever pays a
-    trace.  ``exec_stats`` surfaces that accounting (one "trace" per new
-    shape, everything after is a cache hit inside jit)."""
+    trace.  ``exec_stats()["traces"]`` counts what JAX really did: each
+    run of the traced Python body, plus each new compiled variant that a
+    call added without one (an input of a new placement, such as a host
+    array where warm-up passed device arrays, compiles again on the same
+    trace)."""
 
     def __init__(self, mods: list[ModuleGraph], plans: list[Plan] | None,
                  use_pallas: bool):
@@ -215,12 +224,19 @@ class CompiledNetwork:
         self._freeze_fn = lowered.freeze
         self.needs_calibration = lowered.needs_calibration
         self.ema_modules = lowered.ema_modules
-        self._jitted = jax.jit(lowered.run)
+        self._traced = 0                # runs of the traced body
+
+        def run(tree, x):
+            self._count_trace()         # runs only while JAX traces
+            return lowered.run(tree, x)
+        self._jitted = jax.jit(run)
         # donating variant of the same program: the caller hands over the
         # input-batch buffer and XLA reuses it instead of allocating (one
         # copy saved per call on the serving hot path, where the padded
         # batch is drain-loop-owned and never read again)
-        self._jitted_donate = jax.jit(lowered.run, donate_argnums=(1,))
+        self._jitted_donate = jax.jit(run, donate_argnums=(1,))
+        # (shape, dtype, donate) keys already dispatched: scopes the
+        # donation-warning filter and timed_call's pre-trace
         self._shapes_seen: set = set()
         self._exec = {"calls": 0, "traces": 0, "prepares": 0,
                       "donated_calls": 0, "donated_bytes": 0,
@@ -272,13 +288,14 @@ class CompiledNetwork:
                else _next_prepare_generation())
         return PreparedParams(tree, gen, placement)
 
+    def _count_trace(self) -> None:
+        with self._stats_lock:
+            self._traced += 1
+            self._exec["traces"] += 1
+
     def _count_call(self, x, donate: bool) -> None:
-        key = (tuple(x.shape), str(getattr(x, "dtype", "f32")), donate)
         nbytes = int(getattr(x, "nbytes", 0))
         with self._stats_lock:
-            if key not in self._shapes_seen:
-                self._shapes_seen.add(key)
-                self._exec["traces"] += 1
             self._exec["calls"] += 1
             if donate:
                 self._exec["donated_calls"] += 1
@@ -291,14 +308,25 @@ class CompiledNetwork:
         # fault-injection site, BEFORE any dispatch or donation: an
         # injected dispatch failure leaves the caller's buffer intact
         faults.trip("dispatch", device=self.devices)
-        first = ((tuple(x.shape), str(getattr(x, "dtype", "f32")), donate)
-                 not in self._shapes_seen)
+        key = (tuple(x.shape), str(getattr(x, "dtype", "f32")), donate)
+        first = key not in self._shapes_seen
+        self._shapes_seen.add(key)
         self._count_call(x, donate)
-        tree = _unwrap(prepared)
+        fn = self._jitted_donate if donate else self._jitted
         with _quiet_donation() if (first and donate) else nullcontext():
-            if donate:
-                return self._jitted_donate(tree, x)
-            return self._jitted(tree, x)
+            return self._call_counted(fn, _unwrap(prepared), x)
+
+    def _call_counted(self, fn, *args):
+        """``fn(*args)``, adding to ``traces`` each compiled variant the
+        call added with no run of the traced body: an old trace compiled
+        again for a new input placement."""
+        n0, traced0 = _variants(fn), self._traced
+        out = fn(*args)
+        silent = _variants(fn) - n0 - (self._traced - traced0)
+        if silent > 0:
+            with self._stats_lock:
+                self._exec["traces"] += silent
+        return out
 
     def timed_call(self, prepared, x, *, donate: bool = False):
         """Synchronous, measured forward: ``(out, [wall_seconds])``.  The
@@ -380,8 +408,10 @@ class PipelinedEngine:
         self.needs_calibration = lowered.needs_calibration
         self.ema_modules = lowered.ema_modules
         self.stages = lowered.stages
+        self._traced = 0                # runs of a stage's traced body
         self._jitted = [
-            jax.jit(s.fn) if i == 0 else jax.jit(s.fn, donate_argnums=(2,))
+            jax.jit(self._counted(s.fn)) if i == 0
+            else jax.jit(self._counted(s.fn), donate_argnums=(2,))
             for i, s in enumerate(self.stages)]
         self._shapes_seen: set = set()
         self._env_bytes: dict[tuple, int] = {}   # per input shape, at trace
@@ -403,6 +433,16 @@ class PipelinedEngine:
 
     capture_scales = CompiledNetwork.capture_scales
     refine_scales = CompiledNetwork.refine_scales
+    _count_trace = CompiledNetwork._count_trace
+    _call_counted = CompiledNetwork._call_counted
+
+    def _counted(self, fn):
+        """``fn`` counting each run of its traced body in ``traces``: the
+        count is per stage, so one new input shape adds one per stage."""
+        def stage(*args):
+            self._count_trace()         # runs only while JAX traces
+            return fn(*args)
+        return stage
 
     def _slices(self, prepared) -> list:
         """Per-stage prepared-parameter slices (tiny host-side dicts; each
@@ -418,14 +458,10 @@ class PipelinedEngine:
         # failure stays untagged: it is an error, never a failover
         faults.trip("stage", device=stage.device, stage=s)
         xin = x if stage.needs_input else ()
-        return self._jitted[s](slices[s], xin, env)
+        return self._call_counted(self._jitted[s], slices[s], xin, env)
 
     def _count_call(self, x, donated_env_bytes: int) -> None:
-        key = (tuple(x.shape), str(getattr(x, "dtype", "f32")))
         with self._stats_lock:
-            if key not in self._shapes_seen:
-                self._shapes_seen.add(key)
-                self._exec["traces"] += 1
             self._exec["calls"] += 1
             if len(self.stages) > 1:
                 self._exec["donated_calls"] += 1
@@ -447,8 +483,8 @@ class PipelinedEngine:
         caller's ``x`` is never consumed either way (inter-stage donation
         is always on)."""
         faults.trip("dispatch", device=self.devices)
-        first = ((tuple(x.shape), str(getattr(x, "dtype", "f32")))
-                 not in self._shapes_seen)
+        key = (tuple(x.shape), str(getattr(x, "dtype", "f32")))
+        first = key not in self._shapes_seen
         slices = self._slices(prepared)
         env: dict = {}
         envs = []
@@ -457,6 +493,7 @@ class PipelinedEngine:
                 env = self._dispatch(slices, x, env, s)
                 if s + 1 < len(self.stages):
                     envs.append(env)
+        self._shapes_seen.add(key)
         self._count_call(x, self._env_nbytes(x, envs))
         return env["__out"]
 

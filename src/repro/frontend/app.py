@@ -54,6 +54,14 @@ PR-6 contract), and the door answers each of them before the sockets
 close.  A drain never hangs: the shutdown call itself is bounded and the
 fence guarantees the in-flight set only shrinks.
 
+**Spans.**  Where the backend has a span log (``LocalBackend.spans``, the
+server's ``metrics.spans``) and it is on, each ``/v1/infer`` request gets
+an id at the door and five spans under it: ``door.read`` (the body, after
+the head), ``door.decode`` and ``door.submit`` (in ``LocalBackend.infer``),
+``door.encode`` and ``door.write`` (the answer).  The server's own spans
+for the request carry the same id.  A backend without a log (the
+``Router``) records nothing.
+
 ``faults.trip("conn")`` fires per parsed request head (the
 connection-loop trigger point: the error is answered typed and the
 socket survives) and ``faults.trip("http")`` fires in the handler
@@ -169,6 +177,11 @@ class LocalBackend:
         self.sheds_by_class: dict[int, int] = {}
         self._drain_result: dict | None = None
 
+    @property
+    def spans(self):
+        """The server's span log, which the door records its spans in."""
+        return self.server.metrics.spans
+
     # -- admission (pre-body: nothing here touches the payload) ------------
 
     def admit(self, priority: int = 1):
@@ -202,19 +215,28 @@ class LocalBackend:
         arrives as JSON-base64 fields, a raw binary frame under
         ``_tensor``, or pre-decoded under ``_x``; a 200 body carries the
         served row un-encoded under ``_row`` (the door encodes it at the
-        edge, in the client's negotiated framing)."""
+        edge, in the client's negotiated framing).  ``_rid`` is the
+        request's id in the span log, set by the door while it is on."""
+        spans = self.server.metrics.spans
+        rid = payload.get("_rid", 0) if spans.on else 0
         try:
             faults.trip("http")
+            t0 = time.monotonic_ns() if rid else 0
             if "_tensor" in payload:
                 x = wire.decode_tensor(payload["_tensor"])
             elif "_x" in payload:
                 x = payload["_x"]
             else:
                 x = wire.decode_array(payload)
+            t1 = time.monotonic_ns() if rid else 0
             fut = self.server.submit(
                 payload["network"], x,
                 priority=int(payload.get("priority", 1)),
-                deadline_ms=payload.get("deadline_ms"))
+                deadline_ms=payload.get("deadline_ms"),
+                request_id=rid or None)
+            if rid:
+                spans.add("door.decode", t0, t1, rid)
+                spans.add("door.submit", t1, time.monotonic_ns(), rid)
         except Exception as e:
             reply = wire.error_reply(e)
             if reply[0] == 400:
@@ -296,6 +318,7 @@ class FrontDoor:
     def __init__(self, backend, *, host: str = "127.0.0.1", port: int = 0,
                  idle_timeout_s: float = 30.0, conn_inflight: int = 8):
         self.backend = backend
+        self.spans = getattr(backend, "spans", None)
         self.host = host
         self.port = port
         self.idle_timeout_s = idle_timeout_s
@@ -360,10 +383,10 @@ class FrontDoor:
                                                   reader)
                 if item is None:
                     break               # transport died mid-body
-                result, force_close = item
+                result, force_close, rid = item
                 keep = keep and not force_close
                 pending[0] += 1
-                await queue.put((result, keep, headers.get("accept")))
+                await queue.put((result, keep, headers.get("accept"), rid))
                 if not keep:
                     break
             await queue.put(None)
@@ -394,11 +417,12 @@ class FrontDoor:
         keep CONSUMING (awaiting each result, dropping the bytes) so the
         reader's bounded queue can never wedge a backend task."""
         broken = False
+        spans = self.spans
         while True:
             item = await queue.get()
             if item is None:
                 return
-            result, keep, accept = item
+            result, keep, accept, rid = item
             try:
                 if isinstance(result, tuple):
                     status, body, extra = result
@@ -409,10 +433,17 @@ class FrontDoor:
             pending[0] -= 1
             if broken:
                 continue
+            # rid is nonzero only where the span log was on at the read
+            on = rid and spans.on
             try:
-                writer.write(self._encode(status, body, extra, keep,
-                                          accept))
+                t0 = time.monotonic_ns() if on else 0
+                data = self._encode(status, body, extra, keep, accept)
+                t1 = time.monotonic_ns() if on else 0
+                writer.write(data)
                 await writer.drain()
+                if on:
+                    spans.add("door.encode", t0, t1, rid)
+                    spans.add("door.write", t1, time.monotonic_ns(), rid)
             except Exception:
                 broken = True
                 continue
@@ -440,44 +471,49 @@ class FrontDoor:
 
     async def _read_and_route(self, method: str, path: str, headers: dict,
                               reader):
-        """(result, force_close) for one parsed request head — result is
-        a (status, body, headers) tuple answered immediately, or an
-        asyncio future for an in-flight inference.  None means the
-        transport died mid-body (close without answering)."""
+        """(result, force_close, rid) for one parsed request head — result
+        is a (status, body, headers) tuple answered immediately, or an
+        asyncio future for an in-flight inference; rid is the inference's
+        id in the span log, 0 where the log is off or no inference runs.
+        None means the transport died mid-body (close without
+        answering)."""
         path = path.split("?", 1)[0]
         try:
             faults.trip("conn")
         except Exception as e:
             if not await self._discard_body(reader, headers):
                 return None
-            return wire.error_reply(e), False
+            return wire.error_reply(e), False, 0
         if path == "/healthz" and method == "GET":
-            return await self.backend.health(), False
+            return await self.backend.health(), False, 0
         if path == "/metrics" and method == "GET":
-            return await self.backend.metrics(), False
+            return await self.backend.metrics(), False, 0
         if path == "/drain" and method == "POST":
             await self._discard_body(reader, headers)
-            return await self.backend.drain(), False
+            return await self.backend.drain(), False, 0
         if path != "/v1/infer":
             await self._discard_body(reader, headers)
             return (404, {"error": "not_found", "retryable": False,
-                          "message": path}, {}), False
+                          "message": path}, {}), False, 0
         if method != "POST":
             await self._discard_body(reader, headers)
             return (405, {"error": "method_not_allowed", "retryable": False,
-                          "message": method}, {}), False
+                          "message": method}, {}), False, 0
         # admission BEFORE the body: shed work, not just requests.  The
         # class rides in X-Priority so the weighted buckets can act here.
         shed = self.backend.admit(wire.priority_from_headers(headers))
         if shed is not None:
             if not await self._discard_body(reader, headers):
                 return None
-            return shed, False
+            return shed, False, 0
         if int(headers.get("content-length", 0) or 0) > wire.MAX_BODY_BYTES:
             # refusing to read the body leaves the socket mid-stream:
             # answer 413 and force the connection closed
             return (413, {"error": "payload_too_large",
-                          "retryable": False, "message": ""}, {}), True
+                          "retryable": False, "message": ""}, {}), True, 0
+        spans = self.spans
+        on = spans is not None and spans.on
+        t0 = time.monotonic_ns() if on else 0
         try:
             raw = await wire.read_body(reader, headers)
         except (ConnectionError, asyncio.IncompleteReadError, OSError):
@@ -487,23 +523,27 @@ class FrontDoor:
             try:
                 meta = wire.infer_meta_from_headers(headers)
             except Exception as e:
-                return wire.error_reply(e), False
+                return wire.error_reply(e), False, 0
             payload = {**meta, "_tensor": raw}
         else:
             try:
                 payload = json.loads(raw)
             except Exception as e:
                 return (400, {"error": "bad_request", "retryable": False,
-                              "message": f"invalid JSON: {e}"}, {}), False
+                              "message": f"invalid JSON: {e}"}, {}), False, 0
             if not isinstance(payload, dict):
                 return (400, {"error": "bad_request", "retryable": False,
                               "message": "request body must be a JSON "
-                                         "object"}, {}), False
+                                         "object"}, {}), False, 0
         if headers.get("accept"):
             # ride along so a router hop can forward the negotiation and
             # pass the worker's framed response through untranscoded
             payload["_accept"] = headers["accept"]
-        return asyncio.ensure_future(self.backend.infer(payload)), False
+        rid = 0
+        if on:
+            rid = payload["_rid"] = spans.new_id()
+            spans.add("door.read", t0, time.monotonic_ns(), rid)
+        return asyncio.ensure_future(self.backend.infer(payload)), False, rid
 
     @staticmethod
     async def _discard_body(reader, headers) -> bool:

@@ -68,6 +68,8 @@ class Request:
     #                                      # enqueue); late work is
     #                                      # rejected with DeadlineExceeded
     retries: int = 0                       # dispatch-failure retries spent
+    rid: int = 0                           # request id in the span log
+    #                                      # (0 while the log is off)
     future: Future = field(default_factory=Future)
     t_enqueue: float = field(default_factory=time.monotonic)
 

@@ -1,16 +1,25 @@
-"""Serving metrics: counters + latency percentiles + throughput.
+"""Serving metrics: counters, latency percentiles and a span log.
 
 One ``ServerMetrics`` per ``HeteroServer``; the drain loop records a sample
 per completed request (end-to-end: enqueue -> result ready) and a sample
 per flushed batch, tagged with the batch's lane (network @ resolution /
 priority) so the snapshot reports per-lane p50/p99 next to the server-wide
 numbers.  ``snapshot`` is safe to call from any thread.
+
+``ServerMetrics.spans`` is the server's ``SpanLog``: off unless an operator
+starts it, it then records where each request and batch spent its time on
+the served path (door, batcher, engine), on ``time.monotonic_ns()``.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
+
+# The span log's default bound, in records: about three minutes of a
+# server answering ~900 requests/s, at six spans a request.
+SPAN_CAPACITY = 1 << 20
 
 
 def percentile(values, q: float) -> float:
@@ -27,12 +36,89 @@ def percentile(values, q: float) -> float:
     return float(vs[lo] * (1.0 - frac) + vs[hi] * frac)
 
 
+class SpanLog:
+    """A bounded in-memory log of timed spans on the served path.
+
+    A record is ``(name, t0_ns, t1_ns, id, parent, thread, replica)``:
+
+    - ``t0_ns``/``t1_ns`` are ``time.monotonic_ns()`` readings;
+    - ``id`` is the request's or the batch's id (``new_id``), shared by
+      every span of that request or batch;
+    - ``parent`` is the id of the span that caused this one (a request's
+      ``batcher.queue`` span names the batch that took it), else 0;
+    - ``thread`` is ``threading.get_ident()`` of the recording thread;
+    - ``replica`` is the replica a ``server.dispatch`` or
+      ``server.device_wait`` ran on, else -1.
+
+    The spans, by the layer that records them:
+
+    - door (the front door's event loop): ``door.read`` (the request body,
+      after its head), ``door.decode``, ``door.submit`` (``submit``,
+      including the batcher's ``put``), ``door.encode``, ``door.write``;
+    - batcher (the drain thread): ``batcher.queue`` per request, from its
+      enqueue to the pop that took it into a batch; ``batcher.wait``, the
+      drain thread waiting for a flushable group;
+    - engine (the drain or completion thread): ``server.batch`` from the
+      flush to the last future fulfilled, around ``server.pad`` (bucket
+      pick and padding), ``server.dispatch`` (the engine call, which
+      enqueues the batch's copy to the device and the program),
+      ``server.device_wait`` (until the result is ready: the copy where
+      it is still running, then the program) and ``server.debatch``
+      (copy back, futures, counters).
+
+    Off by default.  While off, each site costs one attribute read of
+    ``on`` and allocates nothing; while on, a site adds a tuple to a
+    ``deque``, which needs no lock.  When more than ``capacity`` records
+    wait to be drained the oldest fall off and ``dropped`` counts them.
+    """
+
+    def __init__(self, capacity: int = SPAN_CAPACITY):
+        self.on = False
+        self.dropped = 0
+        self._recs: deque = deque(maxlen=capacity)
+        self._ids = itertools.count(1)
+        # one tick per record offered, and one per drain (see ``drain``)
+        self._offered = itertools.count()
+        self._base = 0
+
+    def start(self) -> None:
+        self.on = True
+
+    def stop(self) -> None:
+        self.on = False
+
+    def new_id(self) -> int:
+        """A fresh request or batch id (never 0, unique in this log)."""
+        return next(self._ids)
+
+    def add(self, name: str, t0_ns: int, t1_ns: int, id: int = 0,
+            parent: int = 0, replica: int = -1) -> None:
+        next(self._offered)
+        self._recs.append((name, t0_ns, t1_ns, id, parent,
+                           threading.get_ident(), replica))
+
+    def drain(self) -> list:
+        """Every record kept since the last drain, oldest first; adds what
+        fell off the bound meanwhile to ``dropped``."""
+        out = []
+        while True:
+            try:
+                out.append(self._recs.popleft())
+            except IndexError:
+                break
+        tick = next(self._offered)
+        self.dropped += tick - self._base - len(out)
+        self._base = tick + 1
+        return out
+
+
 class ServerMetrics:
     """Thread-safe counters and bounded latency reservoirs (one server-wide,
     one per lane)."""
 
     def __init__(self, reservoir: int = 8192, lane_reservoir: int = 2048):
         self._lock = threading.Lock()
+        self.spans = SpanLog()
         self._t_start = time.monotonic()         # uptime_s in snapshot
         # live-state gauge provider: a callable returning a dict of point-
         # in-time gauges (queue depths, in-flight, pending futures, server
@@ -72,22 +158,17 @@ class ServerMetrics:
         self.drain_flushed = 0                   # batches served during drain
         self.drain_aborted = 0                   # requests Shutdown-rejected
         self.measured_batches = 0                # timed replan sample batches
-        self.replan_checks = 0                   # replanner decisions taken
         self.replans = 0                         # plan hot-migrations served
         self.breaker_states: dict[str, str] = {}  # network -> breaker state
         self.fitted_scales: dict[str, dict] = {}  # network -> fitted coeffs
-        self._t_first = None
-        self._t_last = None
 
-    def record_submit(self, n: int = 1, now: float | None = None):
+    def record_submit(self, n: int = 1):
         with self._lock:
             self.submitted += n
-            if self._t_first is None:
-                self._t_first = now
 
     def record_batch(self, n_real: int, bucket: int, latencies,
-                     by_deadline: bool, now: float | None = None,
-                     lane: str | None = None, replica: str | None = None):
+                     by_deadline: bool, lane: str | None = None,
+                     replica: str | None = None):
         with self._lock:
             self.batches += 1
             self.completed += n_real
@@ -107,7 +188,6 @@ class ServerMetrics:
                 st["lat"].extend(latencies)
                 st["completed"] += n_real
                 st["batches"] += 1
-            self._t_last = now
 
     def record_failure(self, n: int = 1):
         with self._lock:
@@ -148,9 +228,6 @@ class ServerMetrics:
             replicas = {label: (list(st["lat"]), st["completed"],
                                 st["batches"])
                         for label, st in self._replica_lanes.items()}
-            span = ((self._t_last - self._t_first)
-                    if self._t_first is not None and self._t_last is not None
-                    else 0.0)
             out = {
                 "submitted": self.submitted,
                 "completed": self.completed,
@@ -177,13 +254,10 @@ class ServerMetrics:
                 "drain_flushed": self.drain_flushed,
                 "drain_aborted": self.drain_aborted,
                 "measured_batches": self.measured_batches,
-                "replan_checks": self.replan_checks,
                 "replans": self.replans,
                 "breakers": dict(self.breaker_states),
                 "fitted": {k: dict(v)
                            for k, v in self.fitted_scales.items()},
-                "throughput_rps": (self.completed / span if span > 0
-                                   else float("nan")),
                 "uptime_s": time.monotonic() - self._t_start,
             }
         # gauges are read outside the lock: the provider's structures

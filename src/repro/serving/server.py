@@ -704,7 +704,8 @@ class HeteroServer:
             pass
 
     def submit(self, name: str, x, *, priority: int = DEFAULT_PRIORITY,
-               deadline_ms: float | None = None):
+               deadline_ms: float | None = None,
+               request_id: int | None = None):
         """Admit one image; returns a ``concurrent.futures.Future`` whose
         result is that request's logits row.  The image's (H, W) picks the
         resolution lane; ``priority <= 0`` routes to the deadline-critical
@@ -714,7 +715,11 @@ class HeteroServer:
         holding the request has not dispatched by then, the future
         resolves with ``DeadlineExceeded``.  Raises ``ServerClosed`` when
         the server is not running, ``Overloaded`` when the request's lane
-        is at the ``max_queue`` depth bound (load shed)."""
+        is at the ``max_queue`` depth bound (load shed).
+
+        ``request_id`` is the id this request's spans carry in
+        ``metrics.spans`` (the front door passes the id of its own spans);
+        while the log is on, a request without one gets a fresh id."""
         # validation precedes the state check: a malformed request is
         # malformed whether or not the server is running
         with self._lock:
@@ -739,8 +744,10 @@ class HeteroServer:
                                "submit() after shutdown()")
         now = time.monotonic()
         deadline = None if deadline_ms is None else now + deadline_ms * 1e-3
+        spans = self.metrics.spans
+        rid = request_id or (spans.new_id() if spans.on else 0)
         req = Request(name, x, res=res, priority=int(priority),
-                      deadline_s=deadline)
+                      deadline_s=deadline, rid=rid)
         with self._pending_lock:
             self._pending.add(req.future)
         if not self._batcher.put(req, bound=self.max_queue):
@@ -751,7 +758,7 @@ class HeteroServer:
                              f"bound {self.max_queue}",
                              lane=req.lane, bound=self.max_queue,
                              label=lane_label(req.lane))
-        self.metrics.record_submit(now=now)
+        self.metrics.record_submit()
         return req.future
 
     def submit_many(self, name: str, images, *,
@@ -780,27 +787,52 @@ class HeteroServer:
         while not self._stop.is_set():
             reqs: list = []
             try:
+                spans = self.metrics.spans
+                t0 = time.monotonic_ns() if spans.on else 0
                 got = self._batcher.wait_ready(
                     timeout=0.05, buckets_by=self._caps,
                     can_dispatch=self._can_dispatch)
+                # read again: the log may have started during the wait
+                on = spans.on
+                if on:
+                    t_pop = time.monotonic_ns()
+                    if t0:
+                        spans.add("batcher.wait", t0, t_pop)
                 if got is None:
                     continue
                 lane, popped, by_deadline = got
                 reqs = [r for r in popped if r.network != "__wake__"]
                 if reqs:
-                    self._flush(lane, reqs, by_deadline)
+                    bid = 0
+                    if on:
+                        # each request's queue span ends at the pop and
+                        # names the batch that took it
+                        bid = spans.new_id()
+                        for r in reqs:
+                            spans.add("batcher.queue",
+                                      int(r.t_enqueue * 1e9), t_pop,
+                                      r.rid, bid)
+                    self._flush(lane, reqs, by_deadline, bid)
             except Exception as e:      # defensive: the loop must survive
                 self.metrics.count("errors")
                 self.metrics.record_failure(len(reqs))
                 for r in reqs:
                     self._reject(r.future, e)
 
-    def _flush(self, lane: LaneKey, reqs, by_deadline: bool) -> None:
+    def _flush(self, lane: LaneKey, reqs, by_deadline: bool,
+               bid: int = 0) -> None:
         """Dispatch one single-lane batch.  At in_flight == 1 this also
         completes it inline (the fully-serialized pre-pipelining loop);
         otherwise the async result is handed to the completion thread and
         this thread immediately returns to batching — padding of batch i+1
-        overlaps device compute of batch i."""
+        overlaps device compute of batch i.  ``bid`` is the batch's id in
+        the span log (a fresh one is drawn while the log is on)."""
+        spans = self.metrics.spans
+        on = spans.on
+        t_batch = 0
+        if on:
+            t_batch = time.monotonic_ns()
+            bid = bid or spans.new_id()
         with self._lock:
             entry = self._entries.get(lane.network)
         if entry is None:                     # unregistered mid-flight
@@ -834,8 +866,11 @@ class HeteroServer:
                 entry.refresh()
                 self.metrics.record_recompile()
                 engine, prepared = entry.active()
+            t_pad = time.monotonic_ns() if on else 0
             bucket = pick_bucket(len(reqs), entry.buckets)
             xb = pad_batch([r.x for r in reqs], bucket)
+            if on:
+                spans.add("server.pad", t_pad, time.monotonic_ns(), bid, bid)
             if entry.mode == "fallback" and entry.breaker.probe_due(now):
                 self._probe(entry, xb)
                 # a completed recovery redirects THIS batch already
@@ -859,6 +894,7 @@ class HeteroServer:
             # its buffer (exec_stats counts the copies saved).  The host
             # array itself survives donation, so the completion path can
             # still re-dispatch it on the straggler backup engine.
+            t_disp = time.monotonic_ns() if on else 0
             measured = None
             if self._replanner is not None and entry.mode == "primary":
                 entry.measure_seq += 1
@@ -869,6 +905,9 @@ class HeteroServer:
                                                       donate=True, **rkw)
             if measured is None:
                 out = engine(prepared, xb, donate=True, **rkw)
+            if on:
+                spans.add("server.dispatch", t_disp, time.monotonic_ns(),
+                          bid, bid, -1 if replica is None else replica)
         except Exception as e:
             if replica is not None:
                 engine.release(replica)
@@ -885,7 +924,7 @@ class HeteroServer:
             self._maybe_replan(entry, lane, measured, bucket)
         self._inflight_add(1)
         item = (entry, lane, reqs, bucket, by_deadline, xb, out,
-                engine, prepared, replica)
+                engine, prepared, replica, bid, t_batch)
         if self._completions is not None:
             self._outstanding.append(out)
             self._completions.put(item)
@@ -991,7 +1030,6 @@ class HeteroServer:
                     times, batch)
         self.metrics.count("measured_batches")
         decision = rep.consider(entry.name, entry.mods, entry.plans)
-        self.metrics.count("replan_checks")
         if decision.scales is not None:
             self.metrics.set_fitted(entry.name, decision.scales.as_dict())
         if not decision.migrate:
@@ -1056,17 +1094,21 @@ class HeteroServer:
 
     def _complete(self, entry: _Entry, lane: LaneKey, reqs, bucket: int,
                   by_deadline: bool, xb, out, engine=None, prepared=None,
-                  replica=None) -> None:
+                  replica=None, bid: int = 0, t_batch: int = 0) -> None:
         """Resolve one dispatched batch: block until the device result
         lands (under the straggler watchdog), de-batch, fulfil futures.
         Callers release the admission slot (their ``finally``), so a
         crash in here can never double-release it; the replica slot the
-        flush claimed is released HERE, in all paths."""
-        t0 = time.monotonic()
+        flush claimed is released HERE, in all paths.  ``t_batch`` is when
+        the flush began, where the span log was on then (else 0)."""
+        spans = self.metrics.spans
+        on = bool(t_batch) and spans.on
+        t0 = time.monotonic_ns()
         try:
             out = self._watch(entry, xb, out, engine, prepared, replica)
             jax.block_until_ready(out)
-            entry.monitor.record(entry.next_seq(), time.monotonic() - t0)
+            t_ready = time.monotonic_ns()
+            entry.monitor.record(entry.next_seq(), (t_ready - t0) * 1e-9)
             # one host copy, then de-batch as numpy views — per-row device
             # slices would pay 1 dispatch per request
             rows = np.asarray(out)
@@ -1075,10 +1117,16 @@ class HeteroServer:
             for i, r in enumerate(reqs):
                 self._fulfil(r.future, rows[i])
             self.metrics.record_batch(len(reqs), bucket, lats, by_deadline,
-                                      now=now, lane=lane_label(lane),
+                                      lane=lane_label(lane),
                                       replica=(f"{entry.name}/r{replica}"
                                                if replica is not None
                                                else None))
+            if on:
+                t_end = time.monotonic_ns()
+                spans.add("server.device_wait", t0, t_ready, bid, bid,
+                          -1 if replica is None else replica)
+                spans.add("server.debatch", t_ready, t_end, bid, bid)
+                spans.add("server.batch", t_batch, t_end, bid)
         except Exception as e:
             # completion-time failure: the batch's rows get the error — no
             # retry from here (a requeue behind younger completed traffic

@@ -3,10 +3,12 @@ partitioner schemes, compile-cache behaviour, int8 GEMM shape padding, and
 the partitioner's objective validation."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.core.executor import (CompiledNetwork, cache_stats, clear_cache,
-                                 compile_network, plan_signature)
+                                 compile_network, compile_pipelined,
+                                 plan_signature)
 from repro.core.graph import NETWORKS, bottleneck, fire, shuffle_unit
 from repro.core.hetero import init_network, run_network
 from repro.core.partitioner import candidates, partition_network
@@ -139,6 +141,47 @@ def test_cache_opt_out():
     e2 = compile_network(mods, None, cache=False)
     assert e1 is not e2 and isinstance(e1, CompiledNetwork)
     assert cache_stats()["size"] == 0
+
+
+# JAX's own events for a trace and a compile (the benchmark counts the same
+# two inside its measured window)
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+
+@pytest.mark.parametrize("compile_fn", [compile_network, compile_pipelined])
+def test_trace_count_follows_jax_compile_events(compile_fn):
+    """A bucket warmed with device arrays, then called with host arrays as
+    the serving path passes them: the engine's ``traces`` rises exactly
+    when JAX reports a trace or a compile, including the host-array call
+    that compiles again on the warm trace."""
+    clear_cache()
+    mods = [fire("f", 8, 16, 4, 8)]
+    eng = compile_fn(mods, partition_network(mods, paper_faithful=True))
+    prep = eng.prepare(init_network(mods, jax.random.PRNGKey(0)))
+    shape = (4, 8, 8, 16)
+    # every input made before listening: making one may compile
+    dev = [jax.device_put(np.zeros(shape, np.float32)) for _ in range(3)]
+    host = [np.zeros(shape, np.float32) for _ in range(2)]
+    calls = [("warm-up", dev[0], True), ("device again", dev[1], False),
+             ("host", host[0], True), ("host again", host[1], False),
+             ("device after host", dev[2], False)]
+    events = []
+
+    def listen(event, _secs, **_kw):
+        if event in COMPILE_EVENTS:
+            events.append(event)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for label, x, compiles in calls:
+            traces, seen = eng.exec_stats()["traces"], len(events)
+            jax.block_until_ready(eng(prep, x, donate=True))
+            rose = eng.exec_stats()["traces"] - traces
+            fired = events[seen:]
+            assert (rose > 0) == bool(fired) == compiles, (label, rose,
+                                                           fired)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
 
 
 # --- int8 GEMM arbitrary shapes (satellite) --------------------------------

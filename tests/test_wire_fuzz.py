@@ -214,7 +214,8 @@ class _FakeServer:
         self.state = "running"
         self.metrics = ServerMetrics()
 
-    def submit(self, name, x, *, priority=1, deadline_ms=None):
+    def submit(self, name, x, *, priority=1, deadline_ms=None,
+               request_id=None):
         if name != "tiny":
             raise KeyError(f"unknown network {name!r}")
         fut = concurrent.futures.Future()
