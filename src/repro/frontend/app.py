@@ -26,6 +26,15 @@ the client self-inflicted the queue).  Both framings of
 ``application/x-tensor`` request bodies, with the response framing
 negotiated via ``Accept``.
 
+**Body read.**  Each connection is a ``_Connection`` (an
+``asyncio.BufferedProtocol``).  After the head is admitted and passes the
+size check, the body gets a buffer of its own (``np.empty`` of its
+``Content-Length``) and the socket writes into it directly: one copy,
+the kernel's, apart from the bytes that arrived in the same read as the
+head.  A binary body then decodes as a view of that buffer
+(``wire.decode_tensor``); a JSON body parses from a ``bytes`` copy.
+``direct_bodies`` counts the bodies read this way.
+
 **Admission path** (cheapest check first, all before deserialization):
 
   1. drain fence / server state      -> 503 ``shutdown``/``server_closed``
@@ -75,12 +84,16 @@ import json
 import threading
 import time
 
+import numpy as np
+
 from repro.frontend import wire
 from repro.runtime import faults
 from repro.serving.errors import DeadlineExceeded, ServerClosed, Shutdown
 
 DRAIN_BUDGET_S = 10.0
 DEFAULT_LANE_WEIGHTS = {0: 3.0, 1: 1.0}
+HEAD_LIMIT = 1 << 16    # unread bytes a connection holds between bodies
+HEAD_READ = 1 << 12     # the most one socket read takes between bodies
 
 
 class TokenBucket:
@@ -304,6 +317,167 @@ class LocalBackend:
         return 200, self._drain_result, {}
 
 
+class _Connection(asyncio.BufferedProtocol):
+    """One door socket, read without intermediate copies.
+
+    Between bodies the socket reads at most ``HEAD_READ`` bytes at a time
+    into a small head buffer, which ``readline`` parses.  Once a head is
+    admitted, ``read_body`` gives the body a buffer of its own and hands
+    the socket a view of its unfilled part, so the kernel writes the body
+    bytes once, into the array the request then decodes as a view; only
+    the bytes that came in the same read as the head are copied.  Writes
+    go straight to the transport, and ``drain`` waits out
+    ``pause_writing``."""
+
+    def __init__(self, door: "FrontDoor"):
+        self._door = door
+        self.transport = None
+        self._head = bytearray(HEAD_LIMIT)
+        self._hv = memoryview(self._head)
+        self._lo = self._hi = 0         # unread head bytes: _head[_lo:_hi]
+        self._body: memoryview | None = None  # pending body, unfilled part
+        self._waiter: asyncio.Future | None = None
+        self._eof = False
+        self._exc: BaseException | None = None
+        self._lost = False
+        self._read_paused = False
+        self._write_paused = False
+        self._drain_waiter: asyncio.Future | None = None
+        self._closed: asyncio.Future | None = None
+        self._task: asyncio.Task | None = None
+
+    # -- called by the transport -------------------------------------------
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        loop = asyncio.get_running_loop()
+        self._closed = loop.create_future()
+        self._task = loop.create_task(self._door._handle(self))
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        if self._body is not None:
+            return self._body
+        if self._lo == self._hi:
+            self._lo = self._hi = 0
+        elif self._lo and HEAD_LIMIT - self._hi < HEAD_READ:
+            n = self._hi - self._lo
+            self._head[:n] = self._hv[self._lo:self._hi]
+            self._lo, self._hi = 0, n
+        return self._hv[self._hi:self._hi + HEAD_READ]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        if self._body is not None:
+            self._body = self._body[nbytes:]
+            if len(self._body):
+                return
+            self._body = None
+        else:
+            self._hi += nbytes
+            if self._hi - self._lo >= HEAD_LIMIT:
+                self._read_paused = True    # full: nothing to hand out
+                self.transport.pause_reading()
+        self._wake(self._waiter)
+
+    def eof_received(self) -> bool:
+        self._eof = True
+        self._wake(self._waiter)
+        return True                     # half-closed: still answer
+
+    def connection_lost(self, exc) -> None:
+        self._lost = self._eof = True
+        self._exc = exc
+        self._wake(self._waiter)
+        self._wake(self._drain_waiter)
+        self._wake(self._closed)
+
+    def pause_writing(self) -> None:
+        self._write_paused = True
+
+    def resume_writing(self) -> None:
+        self._write_paused = False
+        self._wake(self._drain_waiter)
+
+    # -- called by the door ------------------------------------------------
+
+    @staticmethod
+    def _wake(fut) -> None:
+        if fut is not None and not fut.done():
+            fut.set_result(None)
+
+    async def _wait(self) -> None:
+        self._waiter = asyncio.get_running_loop().create_future()
+        try:
+            await self._waiter
+        finally:
+            self._waiter = None
+
+    def _consume(self, n: int) -> None:
+        self._lo += n
+        if self._read_paused:
+            self._read_paused = False
+            self.transport.resume_reading()
+
+    async def readline(self) -> bytes:
+        """The next line with its ``\\n``; at EOF the unterminated rest,
+        then ``b""`` (``StreamReader.readline``'s contract)."""
+        while True:
+            if self._exc is not None:
+                raise self._exc
+            i = self._head.find(b"\n", self._lo, self._hi)
+            if i >= 0 or self._eof:
+                end = i + 1 if i >= 0 else self._hi
+                line = bytes(self._hv[self._lo:end])
+                self._consume(end - self._lo)
+                return line
+            if self._hi - self._lo >= HEAD_LIMIT:
+                raise ValueError(f"request head line over {HEAD_LIMIT} "
+                                 f"bytes")
+            await self._wait()
+
+    async def read_body(self, n: int) -> np.ndarray:
+        """The next ``n`` bytes, in a buffer of their own (uninitialised,
+        so a stalled client commits no more memory than it sent).  Raises
+        ``IncompleteReadError`` if the stream ends first."""
+        body = np.empty(n, np.uint8)
+        view = memoryview(body)
+        k = min(n, self._hi - self._lo)
+        view[:k] = self._hv[self._lo:self._lo + k]
+        self._consume(k)
+        if k == n:
+            return body
+        self._body = view[k:]
+        try:
+            while self._body is not None:
+                if self._exc is not None:
+                    raise self._exc
+                if self._eof:
+                    got = n - len(self._body)
+                    raise asyncio.IncompleteReadError(bytes(view[:got]), n)
+                await self._wait()
+        finally:
+            self._body = None
+        return body
+
+    def write(self, data) -> None:
+        self.transport.write(data)
+
+    async def drain(self) -> None:
+        if self._write_paused and not self._lost:
+            self._drain_waiter = asyncio.get_running_loop().create_future()
+            try:
+                await self._drain_waiter
+            finally:
+                self._drain_waiter = None
+        if self._lost:
+            raise ConnectionResetError("connection lost")
+
+    def close(self) -> None:
+        self.transport.close()
+
+    async def wait_closed(self) -> None:
+        await self._closed
+
+
 class FrontDoor:
     """The HTTP surface: routes requests on one asyncio server to any
     backend exposing ``admit``/``infer``/``health``/``metrics``/``drain``
@@ -327,10 +501,11 @@ class FrontDoor:
         self.requests = 0
         self.connections = 0
         self.keepalive_reuses = 0       # requests beyond a socket's first
+        self.direct_bodies = 0          # bodies read into their own buffer
 
     async def start(self) -> "FrontDoor":
-        self._srv = await asyncio.start_server(self._handle, self.host,
-                                               self.port)
+        self._srv = await asyncio.get_running_loop().create_server(
+            lambda: _Connection(self), self.host, self.port)
         self.port = self._srv.sockets[0].getsockname()[1]
         return self
 
@@ -349,8 +524,7 @@ class FrontDoor:
 
     # -- connection handler ------------------------------------------------
 
-    async def _handle(self, reader: asyncio.StreamReader,
-                      writer: asyncio.StreamWriter) -> None:
+    async def _handle(self, conn: _Connection) -> None:
         """One keep-alive connection: this reader loop parses request
         heads and bodies IN ORDER, admission-checks between them, and
         enqueues each request's (future, keepalive, accept) for the
@@ -359,13 +533,13 @@ class FrontDoor:
         self.connections += 1
         queue: asyncio.Queue = asyncio.Queue(maxsize=self.conn_inflight)
         pending = [0]                   # enqueued, not yet answered
-        wtask = asyncio.ensure_future(self._writer_loop(writer, queue,
+        wtask = asyncio.ensure_future(self._writer_loop(conn, queue,
                                                         pending))
         first = True
         try:
             while not wtask.done():
                 try:
-                    head = await asyncio.wait_for(wire.read_head(reader),
+                    head = await asyncio.wait_for(wire.read_head(conn),
                                                   self.idle_timeout_s)
                 except asyncio.TimeoutError:
                     if pending[0] > 0:
@@ -380,7 +554,7 @@ class FrontDoor:
                 first = False
                 keep = wire.wants_keepalive(version, headers)
                 item = await self._read_and_route(method, path, headers,
-                                                  reader)
+                                                  conn)
                 if item is None:
                     break               # transport died mid-body
                 result, force_close, rid = item
@@ -395,8 +569,8 @@ class FrontDoor:
             pass                        # client went away: nothing to answer
         except Exception as e:          # defensive: no traceback on the wire
             try:
-                writer.write(wire.response_bytes(*wire.error_reply(e)))
-                await writer.drain()
+                conn.write(wire.response_bytes(*wire.error_reply(e)))
+                await conn.drain()
             except Exception:
                 pass
         finally:
@@ -407,12 +581,12 @@ class FrontDoor:
                 except (asyncio.CancelledError, Exception):
                     pass
             try:
-                writer.close()
-                await writer.wait_closed()
+                conn.close()
+                await conn.wait_closed()
             except Exception:
                 pass
 
-    async def _writer_loop(self, writer, queue, pending) -> None:
+    async def _writer_loop(self, conn, queue, pending) -> None:
         """Answer queued requests in order.  On a broken client socket,
         keep CONSUMING (awaiting each result, dropping the bytes) so the
         reader's bounded queue can never wedge a backend task."""
@@ -439,8 +613,8 @@ class FrontDoor:
                 t0 = time.monotonic_ns() if on else 0
                 data = self._encode(status, body, extra, keep, accept)
                 t1 = time.monotonic_ns() if on else 0
-                writer.write(data)
-                await writer.drain()
+                conn.write(data)
+                await conn.drain()
                 if on:
                     spans.add("door.encode", t0, t1, rid)
                     spans.add("door.write", t1, time.monotonic_ns(), rid)
@@ -470,7 +644,7 @@ class FrontDoor:
         return wire.response_bytes(status, body, extra, keepalive=keep)
 
     async def _read_and_route(self, method: str, path: str, headers: dict,
-                              reader):
+                              conn):
         """(result, force_close, rid) for one parsed request head — result
         is a (status, body, headers) tuple answered immediately, or an
         asyncio future for an in-flight inference; rid is the inference's
@@ -481,7 +655,7 @@ class FrontDoor:
         try:
             faults.trip("conn")
         except Exception as e:
-            if not await self._discard_body(reader, headers):
+            if not await self._discard_body(conn, headers):
                 return None
             return wire.error_reply(e), False, 0
         if path == "/healthz" and method == "GET":
@@ -489,21 +663,21 @@ class FrontDoor:
         if path == "/metrics" and method == "GET":
             return await self.backend.metrics(), False, 0
         if path == "/drain" and method == "POST":
-            await self._discard_body(reader, headers)
+            await self._discard_body(conn, headers)
             return await self.backend.drain(), False, 0
         if path != "/v1/infer":
-            await self._discard_body(reader, headers)
+            await self._discard_body(conn, headers)
             return (404, {"error": "not_found", "retryable": False,
                           "message": path}, {}), False, 0
         if method != "POST":
-            await self._discard_body(reader, headers)
+            await self._discard_body(conn, headers)
             return (405, {"error": "method_not_allowed", "retryable": False,
                           "message": method}, {}), False, 0
         # admission BEFORE the body: shed work, not just requests.  The
         # class rides in X-Priority so the weighted buckets can act here.
         shed = self.backend.admit(wire.priority_from_headers(headers))
         if shed is not None:
-            if not await self._discard_body(reader, headers):
+            if not await self._discard_body(conn, headers):
                 return None
             return shed, False, 0
         if int(headers.get("content-length", 0) or 0) > wire.MAX_BODY_BYTES:
@@ -515,7 +689,7 @@ class FrontDoor:
         on = spans is not None and spans.on
         t0 = time.monotonic_ns() if on else 0
         try:
-            raw = await wire.read_body(reader, headers)
+            body = await self._read_body(conn, headers)
         except (ConnectionError, asyncio.IncompleteReadError, OSError):
             return None
         ctype = headers.get("content-type", "")
@@ -524,10 +698,11 @@ class FrontDoor:
                 meta = wire.infer_meta_from_headers(headers)
             except Exception as e:
                 return wire.error_reply(e), False, 0
-            payload = {**meta, "_tensor": raw}
+            # the request owns the body: decode_tensor views it, no copy
+            payload = {**meta, "_tensor": memoryview(body)}
         else:
             try:
-                payload = json.loads(raw)
+                payload = json.loads(body.tobytes())
             except Exception as e:
                 return (400, {"error": "bad_request", "retryable": False,
                               "message": f"invalid JSON: {e}"}, {}), False, 0
@@ -545,15 +720,25 @@ class FrontDoor:
             spans.add("door.read", t0, time.monotonic_ns(), rid)
         return asyncio.ensure_future(self.backend.infer(payload)), False, rid
 
-    @staticmethod
-    async def _discard_body(reader, headers) -> bool:
+    async def _read_body(self, conn: _Connection, headers) -> np.ndarray:
+        """The request's body as a uint8 array it owns, the socket
+        writing into it directly (``_Connection.read_body``); empty when
+        the head declares no length."""
+        n = int(headers.get("content-length", 0) or 0)
+        if n <= 0:
+            return np.empty(0, np.uint8)
+        body = await conn.read_body(n)
+        self.direct_bodies += 1
+        return body
+
+    async def _discard_body(self, conn, headers) -> bool:
         """Drain a rejected request's body so the client can read the
         reply AND the next pipelined request starts at a clean byte
         boundary (a closed pipe mid-upload reads as a transport error,
         and a transport error would be retried — a shed must stay
         typed).  False if the transport died under the read."""
         try:
-            await wire.read_body(reader, headers)
+            await self._read_body(conn, headers)
             return True
         except Exception:
             return False

@@ -43,7 +43,8 @@ typed serving errors (``repro.serving.errors``): ``overloaded`` -> 429 +
 ``retryable`` alone — no isinstance ladder crosses the process boundary.
 
 The HTTP layer is split so the front door can ADMIT OR SHED AFTER THE
-HEADERS, BEFORE the body (``read_head`` then ``read_body``), and since
+HEADERS, BEFORE the body (``read_head``, then the body into a buffer of
+its own, which ``decode_tensor`` views without a copy), and since
 protocol v2 it speaks **keep-alive**: responses carry
 ``Connection: keep-alive`` when the client asked for (or HTTP/1.1
 implies) it, and ``HttpPool`` is the client half — persistent
@@ -141,9 +142,12 @@ def _decode_shape(shape, itemsize: int) -> tuple[int, ...]:
 
 
 def _as_native(a: np.ndarray, dt: np.dtype) -> np.ndarray:
-    """A writable native-order array from a frombuffer view."""
+    """A writable native-order array from a frombuffer view: the view
+    itself where its buffer is writable and it is aligned, else a copy."""
     if dt.byteorder == ">":
         return a.astype(dt.newbyteorder("<"))
+    if a.flags.writeable and a.flags.aligned:
+        return a
     return a.copy()
 
 
@@ -153,7 +157,7 @@ def encode_array(a) -> dict:
     """JSON-base64 framing.  The ``dtype`` field is an explicit
     ``<``-prefixed little-endian string and the bytes match it — a
     big-endian input array is byteswapped, never emitted native."""
-    a = np.ascontiguousarray(np.asarray(a))
+    a = np.asarray(a, order="C")         # keeps 0-d (ascontiguousarray not)
     le = _wire_dtype(a.dtype)
     a = a.astype(le, copy=False)
     return {"shape": list(a.shape), "dtype": le.str,
@@ -196,7 +200,7 @@ def encode_tensor(a) -> bytes:
     u16 reserved, then ndim u32 dims — followed by the raw little-endian
     element bytes.  No base64: the frame is ``8 + 4*ndim + nbytes``
     long, ~25% smaller on the wire than the JSON path's base64."""
-    a = np.ascontiguousarray(np.asarray(a))
+    a = np.asarray(a, order="C")         # keeps 0-d (ascontiguousarray not)
     le = _wire_dtype(a.dtype)
     a = a.astype(le, copy=False)
     if a.ndim > MAX_NDIM:
@@ -210,10 +214,16 @@ def encode_tensor(a) -> bytes:
 def decode_tensor(buf) -> np.ndarray:
     """Decode one binary tensor frame; every malformed frame raises
     ``WireDecodeError`` — bad magic, unknown dtype code, truncated
-    header, or a byte count that disagrees with the declared shape."""
+    header, or a byte count that disagrees with the declared shape.
+
+    A writable buffer (a ``bytearray``, or the door's ``memoryview`` of
+    a body the request owns) decodes as a view of it, with no copy; a
+    read-only one (``bytes``) decodes to a writable copy."""
     if not isinstance(buf, (bytes, bytearray, memoryview)):
         raise WireDecodeError("tensor body must be bytes")
-    buf = bytes(buf)
+    buf = memoryview(buf)
+    if not (buf.c_contiguous and buf.ndim == 1 and buf.itemsize == 1):
+        buf = memoryview(buf.tobytes())
     if len(buf) < _TENSOR_HEAD.size:
         raise WireDecodeError(f"tensor frame truncated at {len(buf)} "
                               f"bytes")
@@ -395,6 +405,8 @@ def wants_keepalive(version: str, headers: dict) -> bool:
 
 
 async def read_body(reader: asyncio.StreamReader, headers: dict) -> bytes:
+    """A response body on the client half (``HttpPool``); the door reads
+    request bodies through its own connection protocol."""
     n = int(headers.get("content-length", 0) or 0)
     if n <= 0:
         return b""

@@ -149,6 +149,38 @@ def test_encode_pins_little_endian_and_decode_byteswaps():
         == x.astype("<f4").tobytes()
 
 
+def _tensor_buffers(frame: bytes):
+    """The buffer types ``decode_tensor`` takes: ``bytes``, ``bytearray``,
+    and a writable and a read-only ``memoryview``."""
+    return (frame, bytearray(frame), memoryview(bytearray(frame)),
+            memoryview(frame))
+
+
+def test_decode_tensor_views_writable_buffers_and_copies_bytes():
+    """A writable buffer decodes as a view of it; ``bytes`` (and a
+    read-only view) to a writable copy; every input to the same bits."""
+    rng = np.random.RandomState(1)
+    for name in wire.WIRE_DTYPES:
+        for shape in ((), (3,), (2, 3, 4)):
+            x = np.asarray(rng.randn(*shape) * 50).astype(name)
+            frame = wire.encode_tensor(x)
+            for buf in _tensor_buffers(frame):
+                y = wire.decode_tensor(buf)
+                assert y.tobytes() == x.tobytes(), (name, shape, type(buf))
+                assert y.dtype == x.dtype and y.shape == x.shape
+                assert y.flags.writeable
+                # a view where the buffer is writable and the payload
+                # aligned for its dtype; a copy otherwise
+                payload = np.frombuffer(buf, x.dtype, offset=8 + 4 * x.ndim)
+                viewed = not memoryview(buf).readonly and \
+                    payload.flags.aligned
+                assert np.shares_memory(y, np.frombuffer(buf, np.uint8)) \
+                    == viewed, (name, shape, type(buf))
+            be = x.astype(x.dtype.newbyteorder(">"))
+            z = wire.decode_tensor(bytearray(wire.encode_tensor(be)))
+            assert z.dtype.isnative and z.tobytes() == x.tobytes()
+
+
 def test_zero_size_arrays_cross_both_framings():
     for shape in ((0,), (0, 3), (2, 0, 4)):
         x = np.zeros(shape, dtype=np.float32)
@@ -196,11 +228,19 @@ if HAVE_HYPOTHESIS:
            st.integers(0, 2 ** 32))
     def test_roundtrip_parity_property(name, shape, seed):
         rng = np.random.RandomState(seed % (2 ** 32))
-        x = (rng.randn(*shape) * 100).astype(name)
+        # randn() with no dims is a Python float: asarray keeps 0-d arrays
+        x = np.asarray(rng.randn(*shape) * 100).astype(name)
         a = wire.decode_array(wire.encode_array(x))
-        b = wire.decode_tensor(wire.encode_tensor(x))
-        assert a.tobytes() == b.tobytes() == x.tobytes()
-        assert a.shape == b.shape == x.shape
+        frame = wire.encode_tensor(x)
+        for buf in _tensor_buffers(frame):
+            b = wire.decode_tensor(buf)
+            assert a.tobytes() == b.tobytes() == x.tobytes()
+            assert a.shape == b.shape == x.shape
+            assert a.dtype == b.dtype == x.dtype
+            assert b.flags.writeable
+        big = wire.decode_tensor(wire.encode_tensor(
+            x.astype(x.dtype.newbyteorder(">"))))
+        assert big.dtype.isnative and big.tobytes() == x.tobytes()
 
 
 # --- the door under fire (frontend marker: sockets, no jax compile) ---------
